@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
-
 __all__ = ["pipeline_forward"]
 
 
@@ -99,11 +97,11 @@ def pipeline_forward(
         return p.reshape((n_stages, per_stage) + p.shape[1:])
 
     stacked = jax.tree.map(reshape_params, stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(P(axis), P()),  # params split by stage; x replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked, x)

@@ -50,11 +50,15 @@ bit-identical to the dense slot path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import half_major, interpret_default
 
 __all__ = ["quant_decode_attention_fwd", "quant_decode_attention_paged_fwd"]
 
@@ -62,17 +66,27 @@ _NEG_INF = -1e30
 
 
 def _unpack_dequant(p, scales, group):
-    """(blk, d//2) uint8 + (blk, d//group) -> (blk, d) f32."""
+    """(blk, d//2) uint8 + (blk, d//group) f32 -> (blk, d) f32, columns in
+    ``[low nibbles | high nibbles]`` order (``repro.kernels.half_major``).
+
+    Byte ``i`` holds coordinate ``2i`` (low nibble) and ``2i+1`` (high),
+    so both halves cover the groups in the same order, ``group // 2``
+    columns per group.  The per-group scales are broadcast onto lanes
+    by one select per group: no 3-D reshape and no lane interleave,
+    neither of which Mosaic lowers.
+    """
     pi = p.astype(jnp.int32)
     low = pi & 0xF
     high = (pi >> 4) & 0xF
-    low = jnp.where(low >= 8, low - 16, low)
-    high = jnp.where(high >= 8, high - 16, high)
-    blk = p.shape[0]
-    d = p.shape[1] * 2
-    codes = jnp.stack([low, high], axis=-1).reshape(blk, d)
-    y = codes.astype(jnp.float32).reshape(blk, d // group, group)
-    return (y * scales[..., None]).reshape(blk, d)
+    low = jnp.where(low >= 8, low - 16, low).astype(jnp.float32)
+    high = jnp.where(high >= 8, high - 16, high).astype(jnp.float32)
+    blk, half = p.shape
+    col_group = jax.lax.broadcasted_iota(jnp.int32, (blk, half), 1) // (
+        group // 2)
+    s = jnp.zeros((blk, half), jnp.float32)
+    for g in range(scales.shape[1]):
+        s = jnp.where(col_group == g, scales[:, g:g + 1], s)
+    return jnp.concatenate([low * s, high * s], axis=-1)
 
 
 def _kernel_impl(
@@ -171,12 +185,11 @@ def quant_decode_attention_fwd(
 ) -> jax.Array:
     """Returns out_rot (BH, G, d) f32 in rotated space."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     BH, S, dh = k_packed.shape[0], k_packed.shape[1], q_eff.shape[-1]
     G = q_eff.shape[1]
     W = k_residual.shape[1]
-    blk = min(blk, S)
-    assert S % blk == 0, f"S={S} % blk={blk}"
+    blk = math.gcd(min(blk, S), S)
     n_blocks = S // blk
     scalars = jnp.stack([
         jnp.broadcast_to(packed_len.astype(jnp.int32).reshape(-1), (BH,)),
@@ -213,13 +226,15 @@ def quant_decode_attention_fwd(
             pltpu.VMEM((G, dh), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    perm = half_major(dh)
+    out = pl.pallas_call(
         functools.partial(_kernel, blk=blk, group=group, n_blocks=n_blocks),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, G, dh), jnp.float32),
         interpret=interpret,
-    )(scalars, q_eff, k_packed, k_scales, v_packed, v_scales,
-      k_residual, v_residual)
+    )(scalars, q_eff[..., perm], k_packed, k_scales, v_packed, v_scales,
+      k_residual[..., perm], v_residual[..., perm])
+    return out[..., np.argsort(perm)]
 
 
 @functools.partial(
@@ -254,7 +269,7 @@ def quant_decode_attention_paged_fwd(
     residency is O(allocated pages).  Returns out_rot (BH, G, d) f32.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     H = n_kv_heads
     BH, G, dh = q_eff.shape
     MP = page_table.shape[-1]
@@ -297,11 +312,14 @@ def quant_decode_attention_paged_fwd(
             pltpu.VMEM((G, dh), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    perm = half_major(dh)
+    out = pl.pallas_call(
         functools.partial(_kernel_paged, blk=blk, group=group,
                           n_blocks=n_blocks),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, G, dh), jnp.float32),
         interpret=interpret,
-    )(scalars, page_table.astype(jnp.int32), q_eff,
-      k_packed, k_scales, v_packed, v_scales, k_residual, v_residual)
+    )(scalars, page_table.astype(jnp.int32), q_eff[..., perm],
+      k_packed, k_scales, v_packed, v_scales,
+      k_residual[..., perm], v_residual[..., perm])
+    return out[..., np.argsort(perm)]

@@ -23,32 +23,57 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import half_major, interpret_default
+
 __all__ = ["srft_quant_fwd", "srft_dequant_fwd", "DEFAULT_ROW_TILE"]
 
 DEFAULT_ROW_TILE = 256
 
 
+def _col_group(shape, d: int, group: int, bits: int) -> jax.Array:
+    """Quantization group of each column.  int4 columns are half-major
+    (``repro.kernels.half_major``), so each half holds ``group // 2``
+    columns of every group; int8 columns are in natural order."""
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if bits == 4:
+        return (col % (d // 2)) // (group // 2)
+    return col // group
+
+
 def _quant_kernel(x_ref, m_ref, packed_ref, scales_ref, *, group: int,
                   bits: int):
     x = x_ref[...].astype(jnp.float32)  # (TN, d)
-    m = m_ref[...].astype(jnp.float32)  # (d, d)
+    m = m_ref[...].astype(jnp.float32)  # (d, d), rows in output order
     # rotation on the MXU: y[n, e] = sum_d x[n, d] * m[e, d]
     y = jax.lax.dot_general(
         x, m, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     tn, d = y.shape
+    n_groups = d // group
     qmax = float(2 ** (bits - 1) - 1)
-    yg = y.reshape(tn, d // group, group)
-    absmax = jnp.max(jnp.abs(yg), axis=-1)  # (TN, d//group)
-    scale = jnp.maximum(absmax, 1e-12) / qmax
-    scales_ref[...] = scale
-    q = jnp.rint(yg / scale[..., None])
-    q = jnp.clip(q, -qmax, qmax).astype(jnp.int32).reshape(tn, d)
+    # per-group abs-max by masked lane reductions: Mosaic lowers no
+    # (TN, d) -> (TN, d//group, group) reshape
+    col_group = _col_group((tn, d), d, group, bits)
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (tn, n_groups), 1)
+    ay = jnp.abs(y)
+    scale = jnp.zeros((tn, d), jnp.float32)
+    scales = jnp.zeros((tn, n_groups), jnp.float32)
+    for g in range(n_groups):
+        sg = jnp.max(jnp.where(col_group == g, ay, 0.0), axis=-1,
+                     keepdims=True)
+        sg = jnp.maximum(sg, 1e-12) / qmax
+        scale = jnp.where(col_group == g, sg, scale)
+        scales = jnp.where(out_col == g, sg, scales)
+    scales_ref[...] = scales
+    q = jnp.rint(y / scale)
+    q = jnp.clip(q, -qmax, qmax).astype(jnp.int32)
     if bits == 4:
-        # nibble pack: byte = (q[2i+1] << 4) | (q[2i] & 0xF)
-        even = q[:, 0::2] & 0xF
-        odd = q[:, 1::2] & 0xF
-        packed_ref[...] = ((odd << 4) | even).astype(jnp.uint8)
+        # nibble pack: byte i = (q[2i+1] << 4) | (q[2i] & 0xF); the odd
+        # coordinates are the upper half of the half-major columns
+        half = d // 2
+        packed_ref[...] = (
+            ((q[:, half:] & 0xF) << 4) | (q[:, :half] & 0xF)
+        ).astype(jnp.uint8)
     else:
         packed_ref[...] = q.astype(jnp.int8)
 
@@ -56,33 +81,28 @@ def _quant_kernel(x_ref, m_ref, packed_ref, scales_ref, *, group: int,
 def _dequant_kernel(packed_ref, scales_ref, minv_ref, x_ref, *, group: int,
                     bits: int):
     p = packed_ref[...]
-    tn = p.shape[0]
     if bits == 4:
+        # codes come out half-major; minv's columns were permuted to match
         pi = p.astype(jnp.int32)
         low = pi & 0xF
         high = (pi >> 4) & 0xF
         low = jnp.where(low >= 8, low - 16, low)
         high = jnp.where(high >= 8, high - 16, high)
-        d = p.shape[1] * 2
-        codes = jnp.stack([low, high], axis=-1).reshape(tn, d)
+        codes = jnp.concatenate([low, high], axis=-1)
     else:
         codes = p.astype(jnp.int32)
-        d = p.shape[1]
-    scale = scales_ref[...]  # (TN, d//group)
-    y = (
-        codes.astype(jnp.float32).reshape(tn, d // group, group)
-        * scale[..., None]
-    ).reshape(tn, d)
-    minv = minv_ref[...].astype(jnp.float32)  # (d, d): x = y @ minv.T? no:
-    # ref: x[n, dd] = sum_e y[n, e] * minv[dd, e]
-    x = jax.lax.dot_general(
+    tn, d = codes.shape
+    scales = scales_ref[...]  # (TN, d//group)
+    col_group = _col_group((tn, d), d, group, bits)
+    scale = jnp.zeros((tn, d), jnp.float32)
+    for g in range(d // group):
+        scale = jnp.where(col_group == g, scales[:, g:g + 1], scale)
+    y = codes.astype(jnp.float32) * scale
+    minv = minv_ref[...].astype(jnp.float32)
+    # x[n, dd] = sum_e y[n, e] * minv[dd, e]
+    x_ref[...] = jax.lax.dot_general(
         y, minv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    x_ref[...] = x
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -99,12 +119,14 @@ def srft_quant_fwd(
 ):
     """Fused rotate+quantize+pack.  Returns (packed, scales)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     n, d = x.shape
     assert d % group == 0 and d % 2 == 0
     tn = min(row_tile, n)
     assert n % tn == 0, f"N={n} must divide row_tile={tn}"
     grid = (n // tn,)
+    if bits == 4:
+        m = m[half_major(d)]  # rows in the kernel's column order
     out_cols = d // 2 if bits == 4 else d
     out_dtype = jnp.uint8 if bits == 4 else jnp.int8
     return pl.pallas_call(
@@ -141,12 +163,14 @@ def srft_dequant_fwd(
 ):
     """Fused unpack+dequantize+inverse-rotate.  Returns x (N, d) fp32."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     n = packed.shape[0]
     d = packed.shape[1] * 2 if bits == 4 else packed.shape[1]
     tn = min(row_tile, n)
     assert n % tn == 0
     grid = (n // tn,)
+    if bits == 4:
+        minv = minv[:, half_major(d)]  # columns in the codes' order
     in_cols = packed.shape[1]
     return pl.pallas_call(
         functools.partial(_dequant_kernel, group=group, bits=bits),

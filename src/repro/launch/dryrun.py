@@ -3,7 +3,6 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
-os.environ.setdefault("REPRO_BF16_DOTS", "1")  # TPU-faithful dot dtypes
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh, record memory/cost/collective analysis (EXPERIMENTS.md

@@ -55,7 +55,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import warnings
 from typing import Any, Optional
 
 import jax
@@ -67,21 +66,18 @@ __all__ = ["Sampler", "GREEDY", "Engine", "generate", "draft_tokens"]
 
 
 def resolve_mesh_backend(backend, mesh):
-    """KERNEL -> BLOCKWISE under a mesh (warn once per call site).
+    """Refuse the Pallas KERNEL backend under a mesh.
 
-    The Pallas decode kernel addresses one device's buffers; under GSPMD
-    auto-partitioning there is no shard_map wrapper for it yet, so
-    mesh-sharded engines serve the blockwise jnp path instead (same
-    masked-read semantics, proven bit-identical in tests/test_kernels).
+    The decode kernel addresses one device's buffers and has no
+    shard_map wrapper yet, so a mesh-sharded engine must be asked for
+    BLOCKWISE (the same masked-read semantics in jnp) explicitly.
     """
-    if mesh is None or backend != AttendBackend.KERNEL:
-        return backend
-    warnings.warn(
-        "AttendBackend.KERNEL is single-device (Pallas); falling back to "
-        "BLOCKWISE for the mesh-sharded engine",
-        stacklevel=3,
-    )
-    return AttendBackend.BLOCKWISE
+    if mesh is not None and backend == AttendBackend.KERNEL:
+        raise ValueError(
+            "the 'kernel' backend is single-device (Pallas) and cannot "
+            "serve a mesh-sharded engine; use backend 'blockwise'"
+        )
+    return backend
 
 
 def _serve_policy_ctx(mesh):

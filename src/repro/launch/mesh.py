@@ -7,6 +7,7 @@ dry-run sets XLA_FLAGS for 512 host devices before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "data_axes", "HW"]
 
@@ -14,7 +15,8 @@ __all__ = ["make_production_mesh", "data_axes", "HW"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

@@ -3,7 +3,6 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
-os.environ.setdefault("REPRO_BF16_DOTS", "1")  # TPU-faithful dot dtypes
 os.environ["REPRO_UNROLL_SCANS"] = "1"  # cost_analysis must see every layer
 
 """Depth-extrapolated roofline measurement (§Roofline correctness fix).

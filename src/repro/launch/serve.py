@@ -51,6 +51,7 @@ import os
 import signal
 import threading
 import time
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -94,7 +95,7 @@ def calibrate_lambdas(model, params, tokens, rots: Rotations) -> Rotations:
     return Rotations(k=fit(rots.k, k_act), v=fit(rots.v, v_act))
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--smoke", action="store_true")
@@ -209,8 +210,53 @@ def main():
     ap.add_argument("--calibrate", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+REPO_ROOT = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", ".."))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing
+    is set here.  Otherwise the cache lives at one fixed path inside
+    the checkout (``.jax_cache``): the path is part of the cache key, so
+    a path that moved between runs would never hit.  Returns the
+    directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+class Built(NamedTuple):
+    """What ``build_engine`` made from the CLI arguments."""
+
+    cfg: Any
+    model: Any
+    params: Any
+    policy: Any
+    rots: Any
+    mesh: Any
+    engine: Optional[BatchEngine]  # None: family served single-stream
+
+
+def build_engine(args: argparse.Namespace) -> Built:
+    """Config, params, cache policy, optional lambda calibration, mesh
+    and the continuous-batching ``BatchEngine``: everything a serving
+    entry point needs before its first request, from the arguments of
+    ``build_parser``.  Families with recurrent state come back with
+    ``engine=None`` (served single-stream by ``main``)."""
+    backend = AttendBackend.parse(args.backend)
+    if args.mesh is not None and backend == AttendBackend.KERNEL:
+        raise SystemExit(
+            "error: --backend kernel is single-device (Pallas) and cannot "
+            "serve a --mesh; use --backend blockwise")
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -219,7 +265,9 @@ def main():
         print(f"[note] {cfg.name} has no attention KV cache "
               f"(family={cfg.family}); running its recurrent-state path")
 
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # one compiled program instead of one dispatch per op (full-width
+    # models draw billions of numbers)
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     if args.ckpt_dir:
         from repro.optim.adam import adam_init
 
@@ -233,7 +281,6 @@ def main():
 
     policy_name = "bf16" if args.no_quant else args.policy
     policy = model.cache_policy(policy_name) if cfg.kv_applicable else None
-    backend = AttendBackend.parse(args.backend)
 
     rots = None
     if args.calibrate and policy is not None \
@@ -253,18 +300,9 @@ def main():
             print(f"[calibrate] per-channel lambda in "
                   f"{time.time()-t0:.1f}s")
 
-    sampler = Sampler(temperature=args.temperature, top_k=args.top_k)
-    key = jax.random.PRNGKey(args.seed + 2)
     mesh = _build_mesh(args.mesh)
-    ragged_ok = cfg.kv_applicable and cfg.family in ("dense", "moe", "vlm")
-    if not ragged_ok:
-        it = DataIterator(SyntheticCorpus(args.seed + 1),
-                          batch_per_shard=max(args.requests, 1),
-                          seq_len=args.prompt_len)
-        prompt = jnp.asarray(it.next()["tokens"])
-        return _serve_single_stream(cfg, model, params, prompt, policy,
-                                    backend, sampler, args, key, rots,
-                                    mesh=mesh)
+    if not (cfg.kv_applicable and cfg.family in ("dense", "moe", "vlm")):
+        return Built(cfg, model, params, policy, rots, mesh, None)
 
     window = getattr(policy, "window", 1) if policy is not None else 1
     s_max = args.s_max
@@ -279,7 +317,8 @@ def main():
                           enabled=not args.no_trace)
     engine = BatchEngine(
         model, params, capacity=args.max_batch, s_max=s_max,
-        policy=policy, backend=backend, sampler=sampler,
+        policy=policy, backend=backend,
+        sampler=Sampler(temperature=args.temperature, top_k=args.top_k),
         chunk=args.chunk, rots=rots, key=jax.random.PRNGKey(7),
         paged=args.paged, page_size=args.page_size, n_pages=args.pool_pages,
         prefill_chunk=args.prefill_chunk,
@@ -287,7 +326,25 @@ def main():
         offload_bytes=args.offload_bytes, offload_dir=args.offload_dir,
         spec_k=args.spec_k, trace=trace, mesh=mesh,
     )
-    _install_flight_recorder(trace, args)
+    return Built(cfg, model, params, policy, rots, mesh, engine)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg, model, params, policy, rots, mesh, engine = build_engine(args)
+    if engine is None:
+        it = DataIterator(SyntheticCorpus(args.seed + 1),
+                          batch_per_shard=max(args.requests, 1),
+                          seq_len=args.prompt_len)
+        prompt = jnp.asarray(it.next()["tokens"])
+        return _serve_single_stream(
+            cfg, model, params, prompt, policy,
+            AttendBackend.parse(args.backend),
+            Sampler(temperature=args.temperature, top_k=args.top_k), args,
+            jax.random.PRNGKey(args.seed + 2), rots, mesh=mesh)
+
+    _install_flight_recorder(engine.trace, args)
     pname = policy.name if policy is not None else "-"
     offload = (f", host offload {args.offload_bytes / 2**20:.0f} MiB"
                + (f" (+disk {args.offload_dir})" if args.offload_dir else "")
@@ -410,7 +467,7 @@ def _serve_queue(engine: BatchEngine, policy, args) -> None:
     note = "interrupted; drained" if interrupted else "served"
     print(f"  {note} {len(done)} requests, {n_tok} tokens in "
           f"{t_total:.2f}s -> {n_tok / max(t_total, 1e-9):.1f} tok/s "
-          f"aggregate (CPU; incl. one-time compile)")
+          f"aggregate ({_device_label()}; incl. one-time compile)")
     if args.prefill_chunk:
         print(f"  admission: {engine.n_prefill_chunks} prefill chunks, "
               f"{engine.n_reused_tokens} prompt tokens skipped via "
@@ -482,6 +539,12 @@ def _serve_http(cfg, engine: BatchEngine, policy, args) -> None:
             "queues": pipeline.queue_depths(), "cache": data,
         })
         _write_trace_out(engine.trace, args)
+
+
+def _device_label() -> str:
+    """The device that ran, for the timing lines: ``tpu TPU v5 lite``."""
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind}"
 
 
 def _print_completion(comp) -> None:
@@ -606,7 +669,7 @@ def _serve_single_stream(cfg, model, params, prompt, policy, backend,
           f"({batch * args.prompt_len / t_prefill:.0f} prompt tok/s)")
     print(f"  decode:  {ms_tok:.1f} ms/tok   "
           f"{batch * n_steps / max(t_decode, 1e-9):.1f} tok/s "
-          f"decode-only (CPU; incl. one-time compile)")
+          f"decode-only ({_device_label()}; incl. one-time compile)")
     data = _cache_report(policy, cache.get("attn"))
     _write_stats_json(getattr(args, "stats_json", None), {
         "mode": "single-stream", "cache": data,

@@ -23,6 +23,7 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.checkpoint.manager import CheckpointManager
@@ -63,7 +64,8 @@ def parse_mesh(arg: str | None):
     dims = tuple(int(x) for x in arg.split("x"))
     names = ("data", "model")[: len(dims)] if len(dims) <= 2 else (
         "pod", "data", "model")
-    return jax.make_mesh(dims, names)
+    return jax.make_mesh(dims, names,
+                         axis_types=(AxisType.Auto,) * len(dims))
 
 
 def main():

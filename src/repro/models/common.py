@@ -16,11 +16,6 @@ import numpy as np
 PARAM_DTYPE = jnp.bfloat16
 COMPUTE_DTYPE = jnp.bfloat16
 
-# The CPU backend cannot *execute* bf16 x bf16 -> f32 dots (compiles fine).
-# Tests/benchmarks run with f32 operands; the dry-run sets REPRO_BF16_DOTS=1
-# before importing repro so the lowered HLO is TPU-faithful (bf16 dots).
-BF16_DOTS = os.environ.get("REPRO_BF16_DOTS", "0") == "1"
-
 # XLA cost_analysis counts while-loop bodies ONCE (no trip-count scaling).
 # The roofline fit (benchmarks/roofline_measure.py) lowers small-depth
 # variants with every scan fully unrolled and extrapolates; this flag
@@ -46,12 +41,17 @@ def shard_hint(x, kind: str):
 
 
 def dot_operand(x: jax.Array) -> jax.Array:
-    """Cast a matmul operand to the active dot dtype."""
-    return x.astype(COMPUTE_DTYPE if BF16_DOTS else jnp.float32)
+    """Cast a matmul operand to the dot dtype, chosen at trace time: bf16
+    (fp32 accumulation through ``preferred_element_type``) on every
+    backend but the CPU, whose dot thunk refuses some bf16 x bf16 -> f32
+    contractions at run time (the batched expert einsums), so there the
+    operands are fp32."""
+    cpu = jax.default_backend() == "cpu"
+    return x.astype(jnp.float32 if cpu else COMPUTE_DTYPE)
 
 
 def einsum_f32(spec: str, *ops: jax.Array) -> jax.Array:
-    """einsum with fp32 accumulation and platform-safe operand dtype."""
+    """einsum with ``dot_operand`` operands and fp32 accumulation."""
     return jnp.einsum(
         spec, *(dot_operand(o) for o in ops),
         preferred_element_type=jnp.float32,

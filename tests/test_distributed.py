@@ -45,7 +45,8 @@ def test_sharded_train_step_runs_and_matches_single_device():
     # single-device reference
     p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with mesh:
         psh = pt.make_shardings(pt.param_specs(
             jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh), mesh)
@@ -66,15 +67,15 @@ def test_compressed_psum_inside_shard_map():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import compressed_psum, ef_init
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = jax.make_mesh((8,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 512))
     state = ef_init(x[0])
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P()),
-             out_specs=(P("pod"), P("pod")), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P()),
+             out_specs=(P("pod"), P("pod")), check_vma=False)
     def f(xs, st):
         out, new_st = compressed_psum(xs[0], "pod", st, bits=8)
         return out[None], jax.tree.map(lambda a: a[None], new_st)
@@ -107,7 +108,8 @@ def test_pipeline_forward_matches_sequential():
     for i in range(n_layers):
         ref = layer(ws[i], ref)
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     out = pipeline_forward(layer, ws, x, mesh=mesh, axis="pod",
                            n_layers=n_layers)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
@@ -125,11 +127,13 @@ def test_elastic_resharding_checkpoint():
     with tempfile.TemporaryDirectory() as tmp:
         mgr = CheckpointManager(tmp)
         # save under mesh A (4x2)
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = jax.make_mesh((4, 2), ("data", "model"),
+                               axis_types=(jax.sharding.AxisType.Auto,) * 2)
         wa = jax.device_put(tree["w"], NamedSharding(mesh_a, P("data", "model")))
         mgr.save(5, {"w": wa}, metadata={"mesh": [4, 2]})
         # restore under mesh B (2x4) -- elastic re-mesh
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = jax.make_mesh((2, 4), ("data", "model"),
+                               axis_types=(jax.sharding.AxisType.Auto,) * 2)
         sh_b = NamedSharding(mesh_b, P("data", "model"))
         restored, meta = mgr.restore(
             5, tree, sharding_fn=lambda i, ex: sh_b)
@@ -152,7 +156,8 @@ def test_multipod_mesh_lowers_small_model():
 
     cfg = reduced(get_config("internlm2-1.8b"))
     model = build_model(cfg)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     opt_shapes = jax.eval_shape(adam_init, params_shapes)
     import jax.numpy as jnp

@@ -110,3 +110,14 @@ def test_exotic_family_serving(arch):
         logits, cache = model.decode_step(params, tok, cache)
     assert not bool(jnp.any(jnp.isnan(logits)))
     assert int(cache["pos"]) == 35
+
+
+def test_serve_refuses_kernel_backend_on_mesh():
+    """``serve.py --mesh N --backend kernel`` exits with an error naming
+    the backend to use, before building anything."""
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args(
+        ["--smoke", "--mesh", "2", "--backend", "kernel"])
+    with pytest.raises(SystemExit, match="--backend blockwise"):
+        serve.build_engine(args)

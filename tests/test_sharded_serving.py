@@ -246,12 +246,16 @@ def test_mqa_degrades_to_replication_and_stays_exact(lm_mqa, mesh):
 
 def test_kernel_backend_falls_back_under_mesh(lm, mesh):
     """The Pallas kernel read path is single-device; asking for it on a
-    mesh warns and serves through BLOCKWISE instead of crashing."""
+    mesh is an error that names the backend to use instead, never a
+    silent swap."""
     model, params = lm
-    with pytest.warns(UserWarning, match="single-device"):
-        eng = BatchEngine(model, params, capacity=2, s_max=32,
-                          policy="int4-srft", backend="kernel",
-                          key=jax.random.PRNGKey(7), mesh=mesh)
+    with pytest.raises(ValueError, match="blockwise"):
+        BatchEngine(model, params, capacity=2, s_max=32,
+                    policy="int4-srft", backend="kernel",
+                    key=jax.random.PRNGKey(7), mesh=mesh)
+    eng = BatchEngine(model, params, capacity=2, s_max=32,
+                      policy="int4-srft", backend="blockwise",
+                      key=jax.random.PRNGKey(7), mesh=mesh)
     assert eng.backend is AttendBackend.BLOCKWISE
 
 
