@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.device_idle(ctx)
