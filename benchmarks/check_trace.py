@@ -14,14 +14,15 @@ malformed exporter before a human pastes a broken file into a viewer:
    either contains or is disjoint from its neighbours (1 us epsilon
    for clock rounding).  Overlap without containment means the
    exporter emitted garbage timestamps;
-3. **request coverage** -- every ``tok.stream`` instant must fall
-   inside its request's async ``b``/``e`` window (matched by
-   ``args.rid``): the recorder deliberately closes the request track
-   only after the final tokens streamed, so a token outside its
-   request span is an instrumentation bug.  A missing ``e`` means the
-   request was in flight at snapshot time (open window tolerated); a
-   missing ``b`` is tolerated only when the ring dropped events or the
-   export was windowed (``otherData.dropped > 0`` / ``window_s``);
+3. **request coverage** -- every ``detok`` span (one per streamed
+   token batch) must fall inside its request's async ``b``/``e``
+   window (matched by ``args.rid``): the recorder deliberately closes
+   the request track only after the final tokens streamed, so a token
+   batch outside its request span is an instrumentation bug.  A
+   missing ``e`` means the request was in flight at snapshot time
+   (open window tolerated); a missing ``b`` is tolerated only when
+   the ring dropped events or the export was windowed
+   (``otherData.dropped > 0`` / ``window_s``);
 4. **bound** -- the buffer honored its capacity: recorded events in
    the file never exceed ``otherData.capacity`` (metadata ``M``
    events are synthesized at export and do not count).
@@ -99,36 +100,37 @@ def _nesting_problems(events) -> list[str]:
 
 
 def _coverage_problems(events, other) -> list[str]:
-    """Every tok.stream instant lies inside its request's b/e window."""
+    """Every detok span lies inside its request's b/e window."""
     out = []
     lossy = bool(other.get("dropped")) or other.get("window_s") is not None
     begin: dict = {}
     end: dict = {}
-    toks: list = []
+    batches: list = []
     for ev in events:
         ph = ev.get("ph")
         if ph == "b" and ev.get("name") == "request":
             begin.setdefault(ev["id"], ev["ts"])
         elif ph == "e" and ev.get("name") == "request":
             end[ev["id"]] = ev["ts"]
-        elif ph == "i" and ev.get("name") == "tok.stream":
-            toks.append(ev)
-    for ev in toks:
+        elif ph == "X" and ev.get("name") == "detok":
+            batches.append(ev)
+    for ev in batches:
         rid = (ev.get("args") or {}).get("rid")
         if rid is None:
-            out.append(f"tok.stream at {ev['ts']:.1f}us has no args.rid")
+            out.append(f"detok at {ev['ts']:.1f}us has no args.rid")
             continue
         if rid not in begin:
             if lossy:
                 continue  # the 'b' fell off the ring / outside the window
-            out.append(f"tok.stream rid={rid} has no request 'b' event "
+            out.append(f"detok rid={rid} has no request 'b' event "
                        f"(and the export is complete: dropped=0, "
                        f"no window)")
             continue
         t0 = begin[rid]
         t1 = end.get(rid, float("inf"))  # in-flight at snapshot time
-        if not (t0 - _EPS_US <= ev["ts"] <= t1 + _EPS_US):
-            out.append(f"tok.stream rid={rid} at {ev['ts']:.1f}us outside "
+        s0, s1 = ev["ts"], ev["ts"] + ev["dur"]
+        if not (t0 - _EPS_US <= s0 and s1 <= t1 + _EPS_US):
+            out.append(f"detok rid={rid} [{s0:.1f}, {s1:.1f}]us outside "
                        f"its request span [{t0:.1f}, "
                        f"{'inf' if t1 == float('inf') else f'{t1:.1f}'}]us")
     for rid, t1 in end.items():

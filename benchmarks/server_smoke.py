@@ -190,7 +190,8 @@ def main() -> None:
         problems = check_trace(trace)
         assert not problems, "\n".join(["/debug/trace invalid:"] + problems)
         names = {e["name"] for e in trace["traceEvents"]}
-        for need in ("request", "tok.stream", "decode.chunk", "detok"):
+        for need in ("request", "decode.chunk", "decode.dispatch",
+                     "decode.wait", "decode.post", "detok"):
             assert need in names, f"no {need!r} events in /debug/trace"
         # bucketed admission prefills through admit_packed; chunked
         # admission through prefill.chunk; direct submit through

@@ -222,7 +222,7 @@ def step_logits(engine, backend: AttendBackend):
                                       backend=backend, active=act)
         return logits[:, -1].astype(jnp.float32)
 
-    fn = jax.jit(engine._traced(step))
+    fn = jax.jit(engine._traced(step, "decode_logits"))
     argv = (engine.params, engine.tok, engine.cache, jnp.asarray(active))
     compiled = fn.lower(*argv).compile()
     logits = np.asarray(compiled(*argv))[active]

@@ -87,6 +87,7 @@ or drive ``step()`` directly for token-level streaming.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -415,48 +416,58 @@ class BatchEngine:
             self.n_reuse_hits_host = 0
             self.n_reuse_misses = 0
 
+        # Every program is jitted under a fixed name, so its XLA module
+        # is jit_<name> on the chip whatever the code around it, and the
+        # device trace can be read by program (PERF.md, span table).
         # jit specializes per prompt-length shape on its own; one wrapper
+        def prefill(p, t, c):
+            return self.model.prefill(p, t, c)
+
         self._prefill_fn = jax.jit(
-            self._traced(lambda p, t, c: self.model.prefill(p, t, c)),
+            self._traced(prefill, "prefill"),
             donate_argnums=(2,) if donate else (),
         )
         self._chunk_fns: dict[int, Any] = {}
         self._insert_fn = jax.jit(
-            self._traced(self._insert_impl),
+            self._traced(self._insert_impl, "insert_row"),
             donate_argnums=(0,) if donate else ()
         )
         self._insert_paged_fn = jax.jit(
-            self._traced(self._insert_paged_impl),
+            self._traced(self._insert_paged_impl, "insert_row_paged"),
             donate_argnums=(0,) if donate else ()
         )
         self._reset_fn = jax.jit(
-            self._traced(self._reset_impl),
+            self._traced(self._reset_impl, "reset_rows"),
             donate_argnums=(0,) if donate else ()
         )
+
         # chunked prefill: one jitted chunk dispatch (specializes per
         # (chunk_len, prompt_len) shape pair -- same compilation economy
         # as _prefill_fn), plus the paged-reuse seed/backfill helpers
+        def prefill_chunk(p, t, row, rk, rv):
+            return self.model.prefill_chunk(p, t, row, rk, rv)
+
         self._chunk_prefill_fn = jax.jit(
-            self._traced(lambda p, t, row, rk, rv: self.model.prefill_chunk(
-                p, t, row, rk, rv
-            )),
+            self._traced(prefill_chunk, "prefill_chunk"),
             donate_argnums=(2, 3, 4) if donate else (),
         )
         self._seed_fn = jax.jit(
-            self._traced(self._seed_impl),
+            self._traced(self._seed_impl, "seed_row"),
             donate_argnums=(0,) if donate else ()
         )
         self._import_fn = jax.jit(
-            self._traced(self._import_impl),
+            self._traced(self._import_impl, "import_pages"),
             donate_argnums=(0,) if donate else ()
         )
-        self._raw_view_fn = jax.jit(self._traced(self._raw_view_impl),
-                                    static_argnums=(1, 2))
+        self._raw_view_fn = jax.jit(
+            self._traced(self._raw_view_impl, "raw_view"),
+            static_argnums=(1, 2))
         # packed admission (DESIGN.md §12): slice one row out of a
         # batch-k staging cache (the staging cache is reused for every
         # row, so it is NOT donated here)
         self._slice_axes: Optional[tuple] = None
-        self._slice_row_fn = jax.jit(self._traced(self._slice_row_impl))
+        self._slice_row_fn = jax.jit(
+            self._traced(self._slice_row_impl, "slice_row"))
 
     @property
     def trace(self):
@@ -492,17 +503,21 @@ class BatchEngine:
             else jax.tree.map(jnp.copy, self._rots)
 
     # ---------------------------------------------------------- mesh layout
-    def _traced(self, fn):
-        """Wrap a to-be-jitted callable so tracing runs under the
+    def _traced(self, fn, name: str):
+        """Wrap a to-be-jitted callable under the fixed ``name`` (its
+        XLA module is then ``jit_<name>``), tracing it under the
         serve_exact activation policy when the engine has a mesh
-        (launch/act_sharding, DESIGN.md §16); identity otherwise."""
-        if self.mesh is None:
-            return fn
+        (launch/act_sharding, DESIGN.md §16)."""
+        mesh = self.mesh
 
+        @functools.wraps(fn)
         def inner(*args, **kwargs):
-            with _serve_policy_ctx(self.mesh):
+            if mesh is None:
+                return fn(*args, **kwargs)
+            with _serve_policy_ctx(mesh):
                 return fn(*args, **kwargs)
 
+        inner.__name__ = inner.__qualname__ = name
         return inner
 
     def _shard_cache_tree(self, cache):
@@ -971,7 +986,7 @@ class BatchEngine:
                         jnp.moveaxis(toks, 0, 1),  # (capacity, n_steps)
                         jnp.moveaxis(valid, 0, 1))
 
-            fn = jax.jit(self._traced(run),
+            fn = jax.jit(self._traced(run, "decode_quantum"),
                          donate_argnums=(2,) if self.donate else ())
             self._chunk_fns[n_steps] = fn
         return fn
@@ -1056,7 +1071,7 @@ class BatchEngine:
                         valid, nd, na)
 
             fn = jax.jit(
-                self._traced(run),
+                self._traced(run, "spec_quantum"),
                 donate_argnums=(2, 5, 6) if self.donate else ()
             )
             self._spec_chunk_fns[n_steps] = fn
@@ -1652,7 +1667,11 @@ class BatchEngine:
                         if self._slot_req[s] is None]
                 if not free:
                     return
-                self._start_pending(self._queue.popleft(), free[0])
+                t0a = time.perf_counter()
+                req = self._queue.popleft()
+                self._start_pending(req, free[0])
+                self._trace.span_at("admit.start", t0a, cat="prefill",
+                                    rid=req.rid)
             pend = self._pending
             prompt = np.asarray(pend.req.prompt, np.int32)
             # at least one chunk per quantum even if budget < chunk;
@@ -1671,6 +1690,8 @@ class BatchEngine:
                 pend.n_done += C
                 spent += C
                 self.n_prefill_chunks += 1
+                # ends at dispatch: the chunk's device work runs under
+                # whatever the host does next (the decode.wait after it)
                 self._trace.span_at("prefill.chunk", t0c, cat="prefill",
                                     rid=pend.req.rid, tokens=C,
                                     done=pend.n_done, total=pend.n_total)
@@ -1678,7 +1699,10 @@ class BatchEngine:
                                     time.perf_counter() - t0c)
             if pend.n_done < pend.n_total:
                 return  # budget exhausted; decode now
+            t0i = time.perf_counter()
             ok, ev, comps = self._finalize_pending(round_start)
+            self._trace.span_at("admit.insert", t0i, cat="prefill",
+                                rid=pend.req.rid, inserted=ok)
             events.extend(ev)
             completions.extend(comps)
             if not ok:
@@ -1722,6 +1746,10 @@ class BatchEngine:
         # tokens (clipped to the longest remaining budget -- no masked
         # tail steps when every live request is nearly done)
         n_steps = int(min(self.chunk, self.budget[self.active].max()))
+        # decode.chunk runs from here to the tokens' readback, split into
+        # decode.dispatch (host enqueue, jit cache lookup included) and
+        # decode.wait (the host blocked on the device); decode.post is
+        # the host work after it
         t0d = time.perf_counter()
         n_live = int(self.active.sum())
         self._sample_key, sub = jax.random.split(self._sample_key)
@@ -1735,25 +1763,31 @@ class BatchEngine:
                 self.params, self.tok, self.cache,
                 jnp.asarray(self.active), jnp.asarray(self.budget),
                 self._hist, self._hlen, sub)
-            self.n_drafted += int(nd)
-            self.n_accepted += int(na)
+            t_disp = time.perf_counter()
+            nd, na = int(nd), int(na)
+            self.n_drafted += nd
+            self.n_accepted += na
         else:
             fn = self._chunk_fn(n_steps)
             (self.tok, self.cache, active_dev, budget_dev, toks,
              valid) = fn(self.params, self.tok, self.cache,
                          jnp.asarray(self.active), jnp.asarray(self.budget),
                          sub)
+            t_disp = time.perf_counter()
         toks = np.asarray(toks)
         valid = np.asarray(valid)
         self.budget = np.asarray(budget_dev).copy()
         still_active = np.asarray(active_dev)
-        self._trace.span_at("decode.chunk", t0d, cat="decode",
-                            steps=n_steps, rows=n_live,
-                            spec=self.spec_k is not None)
+        t_read = time.perf_counter()
+        tr = self._trace
+        tr.span_at("decode.dispatch", t0d, cat="decode", t1=t_disp)
+        tr.span_at("decode.wait", t_disp, cat="decode", t1=t_read)
+        tr.span_at("decode.chunk", t0d, cat="decode", t1=t_read,
+                   steps=n_steps, rows=n_live, capacity=self.capacity,
+                   spec=self.spec_k is not None)
         if self.spec_k is not None:
-            self._trace.instant("spec.verify", cat="spec",
-                                drafted=int(nd), accepted=int(na),
-                                rejected=int(nd) - int(na))
+            tr.instant("spec.verify", cat="spec", drafted=nd, accepted=na,
+                       rejected=nd - na)
 
         for slot in range(self.capacity):
             req = self._slot_req[slot]
@@ -1774,6 +1808,7 @@ class BatchEngine:
                                         jnp.asarray(newly_retired))
             if self.paged:
                 self._sync_pool()
+        tr.span_at("decode.post", t_read, cat="decode")
         return events, completions
 
     def run(self, requests: Optional[list[Request]] = None

@@ -166,7 +166,6 @@ class TokenFanout:
                                   sse=sse))
             tr.span_at("detok", t0w, cat="detok", rid=rid, n=len(toks))
             tr.req_add(rid, "detok_s", time.perf_counter() - t0w)
-            tr.instant("tok.stream", cat="token", rid=rid, n=len(toks))
         for comp in completions:
             with self._lock:
                 q = self._streams.pop(comp.rid, None)
@@ -181,7 +180,7 @@ class TokenFanout:
                     m.e2e.record(t - t_arr)
             # popping the timing closes the request's trace track: the
             # "e" event lands HERE, after its last tokens streamed, so
-            # every tok.stream instant falls inside the request span
+            # every detok span falls inside the request span
             timing = tr.req_timing(comp.rid)
             if q is not None:
                 payload = {"rid": comp.rid, "tokens": [], "text": "",
@@ -472,6 +471,7 @@ class ServingPipeline:
 
     def _admission_loop(self) -> None:
         t_newest = None
+        t_hold = None  # first held beat of the hold in progress
         while not self._stop.is_set():
             self._admit_wake.wait(timeout=0.05)
             self._admit_wake.clear()
@@ -498,14 +498,18 @@ class ServingPipeline:
                 if hold:
                     # partial group, arrivals still landing: wait one
                     # beat so the burst packs into one dispatch
-                    self.trace.instant(
-                        "admit.hold", cat="sched",
-                        head_group=self.bucketizer.head_group_len(),
-                        depth=self.bucketizer.depth,
-                    )
+                    if t_hold is None:
+                        t_hold = time.perf_counter()
                     time.sleep(min(self.admit_hold_s, 0.001))
                     self._admit_wake.set()
                 else:
+                    if t_hold is not None:  # one span per hold
+                        self.trace.span_at(
+                            "admit.hold", t_hold, cat="sched",
+                            head_group=self.bucketizer.head_group_len(),
+                            depth=self.bucketizer.depth,
+                        )
+                        t_hold = None
                     t0a = time.perf_counter()
                     moved = self.bucketizer.admit()
                     if moved:

@@ -20,6 +20,16 @@ Two event shapes cover everything the serving stack needs:
   verify result, a COW prefix adoption, a host-tier restore, an
   offload spill, a preemption.  Args carry page counts / tier labels.
 
+Besides what the serving stack records, every enabled recorder takes
+**runtime spans** (category ``runtime``) from two process-wide hooks,
+installed once when the first enabled recorder is built: JAX's
+monitoring events give ``jax.trace`` / ``jax.lower`` / ``jax.compile``
+(``args.fun`` names the function or module; ``jax.compile`` also covers
+a load from the persistent compile cache), and ``gc.callbacks`` gives
+``py.gc`` for every collection of ``GC_MIN_S`` or more (``args.gen``).
+Each lands on the thread that paid for it, nested inside whatever span
+that thread had open, so a host stall is named by what it was.
+
 Requests are correlated across threads by their engine request id:
 :meth:`req_mark` records lifecycle timestamps (``submit`` /
 ``admit`` / ``first_token`` / ``done`` — first mark wins, so a
@@ -40,15 +50,77 @@ still in the ring.
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = ["TraceRecorder"]
 
 _PID = 1  # single process; the pid field is just a constant track group
+
+# JAX monitoring duration events -> runtime span names
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+GC_MIN_S = 1e-3  # shorter collections are not recorded
+
+# Enabled recorders that take runtime spans.  One listener per process
+# forwards to them, so recorders come and go without leaking hooks.
+_live: "weakref.WeakSet[TraceRecorder]" = weakref.WeakSet()
+_live_lock = threading.RLock()  # re-entered when a gc fires inside it
+_hooked = False
+_gc_t0: Optional[float] = None
+
+
+def _runtime_recorders() -> list:
+    with _live_lock:
+        return [r for r in _live if r.enabled]
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    t1 = time.perf_counter()
+    args = {"fun": str(kw.get("fun_name", ""))}
+    for rec in _runtime_recorders():
+        rec._append(t1 - secs, secs, "X", name, "runtime", args)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is None:
+        return
+    dur = time.perf_counter() - t0
+    if dur < GC_MIN_S:
+        return
+    args = {"gen": info.get("generation")}
+    for rec in _runtime_recorders():
+        rec._append(t0, dur, "X", "py.gc", "runtime", args)
+
+
+def _watch_runtime(rec: "TraceRecorder") -> None:
+    """Forward runtime spans to ``rec``; hook the process on first use."""
+    global _hooked
+    with _live_lock:
+        _live.add(rec)
+        if _hooked:
+            return
+        _hooked = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    gc.callbacks.append(_on_gc)
 
 
 class _NullSpan:
@@ -113,6 +185,8 @@ class TraceRecorder:
         self._req_lock = threading.Lock()
         self._req: Dict[int, Dict[str, float]] = {}
         self._req_cap = 8192
+        if self.enabled:
+            _watch_runtime(self)
 
     # ---------------------------------------------------------- hot path
 
@@ -128,13 +202,16 @@ class TraceRecorder:
             return _NULL_SPAN
         return _Span(self, name, cat, args or None)
 
-    def span_at(self, name: str, t0: float, cat: str = "server",
-                **args) -> None:
-        """Record a complete event from ``t0`` (perf_counter) to now."""
+    def span_at(self, name: str, t0: float, cat: str = "server", *,
+                t1: Optional[float] = None, **args) -> None:
+        """Record a complete event from ``t0`` (perf_counter) to ``t1``
+        (default now).  Spans that share a boundary pass the same
+        reading to both, so they nest exactly."""
         if not self.enabled:
             return
-        self._append(t0, time.perf_counter() - t0, "X", name, cat,
-                     args or None)
+        if t1 is None:
+            t1 = time.perf_counter()
+        self._append(t0, t1 - t0, "X", name, cat, args or None)
 
     def instant(self, name: str, cat: str = "server", **args) -> None:
         if not self.enabled:
@@ -183,7 +260,7 @@ class TraceRecorder:
 
         Popping also closes the request's async track (the ``"e"``
         event lands *after* the final tokens streamed, so every
-        ``tok.stream`` instant falls inside its request span).
+        ``detok`` span falls inside its request span).
         Returns ``None`` when disabled or the rid is unknown.
         """
         if not self.enabled:

@@ -100,3 +100,42 @@ def test_srft_quant_kernels_compile(one_chip, bits):
         [((n, cols), code), ((n, D // GROUP), jnp.float32),
          ((D, D), jnp.float32)], one_chip)
     assert "tpu_custom_call" in quant and "tpu_custom_call" in dequant
+
+
+def test_engine_decode_quantum_keeps_its_names(one_chip, monkeypatch):
+    """The engine's decode quantum, at internlm2-1.8b widths (one layer,
+    paged int4-srft, the Pallas kernel), compiled for the chip: its
+    module is ``jit_decode_quantum`` and the kernel's custom call is the
+    instruction ``quant_decode_attention_paged_fwd.<n>``, the names the
+    benchmark's device-trace readers match."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.launch.batch_engine import BatchEngine
+    from repro.models import build_model
+
+    cfg = dataclasses.replace(get_config("internlm2-1.8b"), n_layers=1,
+                              kv_group=GROUP, kv_window=W).validated()
+    mdl = build_model(cfg)
+    params = jax.eval_shape(mdl.init, jax.random.PRNGKey(0))
+    eng = BatchEngine(mdl, params, capacity=2, s_max=4 * PAGE,
+                      policy="int4-srft", backend="kernel", chunk=2,
+                      paged=True, page_size=PAGE,
+                      key=jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    # trace as the chip would: bf16 dot operands, compiled kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = on_chip((params, eng.tok, eng.cache,
+                    jnp.zeros((2,), bool), jnp.zeros((2,), jnp.int32),
+                    eng._sample_key))
+    text = eng._chunk_fn(2).lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_decode_quantum,"), text[:200]
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert any(c.startswith("quant_decode_attention_paged_fwd.")
+               for c in calls), calls

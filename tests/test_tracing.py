@@ -22,6 +22,7 @@ Plus the observability satellites: strict-Prometheus ``/metrics``
 rendering (HELP/TYPE per family, sanitized names, labelled tier
 counters) and the spec-decode rejection counter.
 """
+import gc
 import importlib.util
 import json
 import os
@@ -41,6 +42,7 @@ from repro.launch.server import (
     TraceRecorder,
     make_requests,
 )
+from repro.launch.server import tracing
 from repro.launch.server.pipeline import drain_stream
 from repro.launch.server.stats import ServerMetrics, sanitize_metric_name
 from repro.models import build_model
@@ -127,7 +129,8 @@ def test_span_and_span_at_record_durations():
     t0 = time.perf_counter()
     time.sleep(0.002)
     tr.span_at("at", t0, cat="b", rid=5)
-    evs = [e for e in tr.export()["traceEvents"] if e["ph"] == "X"]
+    evs = [e for e in tr.export()["traceEvents"]
+           if e["ph"] == "X" and e["cat"] != "runtime"]  # not a py.gc
     assert [e["name"] for e in evs] == ["ctx", "at"]
     for e in evs:
         assert e["dur"] >= 1500  # us: the sleep is visible
@@ -241,6 +244,95 @@ def test_write_roundtrip(tmp_path):
     assert not check_trace(obj)
 
 
+def test_span_at_t1_shares_a_boundary():
+    tr = TraceRecorder(capacity=16)
+    t0 = time.perf_counter()
+    t1 = t0 + 0.25
+    tr.span_at("first", t0, t1=t1)
+    tr.span_at("second", t1, t1=t1 + 0.5, k=2)
+    evs = [e for e in tr.export()["traceEvents"]
+           if e["ph"] == "X" and e["cat"] != "runtime"]
+    a, b = evs
+    assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-3)
+    assert b["dur"] == pytest.approx(0.5e6, abs=1e-3)
+    assert b["args"] == {"k": 2}  # t1 is not an arg
+    assert not check_trace(tr.export())
+
+
+# --------------------------------------------------------------------------
+# runtime spans: JAX traces/lowerings/compiles and long GC pauses
+# --------------------------------------------------------------------------
+def _runtime(tr, name=None):
+    return [e for e in tr.export()["traceEvents"]
+            if e["ph"] == "X" and e["cat"] == "runtime"
+            and (name is None or e["name"] == name)]
+
+
+def test_runtime_spans_name_traces_and_compiles():
+    tr = TraceRecorder(capacity=1 << 12)
+
+    def runtime_span_probe(x):
+        return x * 3 + 1
+
+    t0 = time.perf_counter()
+    jax.jit(runtime_span_probe)(np.arange(7.0)).block_until_ready()
+    t1 = time.perf_counter()
+    traced = [e for e in _runtime(tr, "jax.trace")
+              if e["args"]["fun"] == "runtime_span_probe"]
+    compiled = [e for e in _runtime(tr, "jax.compile")
+                if "runtime_span_probe" in e["args"]["fun"]]
+    lowered = [e for e in _runtime(tr, "jax.lower")
+               if "runtime_span_probe" in e["args"]["fun"]]
+    assert traced and compiled and lowered
+    for e in traced + compiled + lowered:
+        start = tr.t0 + e["ts"] * 1e-6
+        assert t0 - 1e-3 <= start
+        assert start + e["dur"] * 1e-6 <= t1 + 1e-3
+
+
+def test_disabled_recorder_records_no_runtime_spans():
+    on = TraceRecorder(capacity=1 << 12)  # the hooks fire meanwhile
+    off = TraceRecorder(capacity=64, enabled=False)
+
+    def disabled_probe(x):
+        return x - 2
+
+    jax.jit(disabled_probe)(np.arange(5.0)).block_until_ready()
+    junk = [[i] for i in range(50_000)]
+    gc.collect()
+    del junk
+    assert any(e["args"]["fun"] == "disabled_probe"
+               for e in _runtime(on, "jax.trace"))
+    assert len(off) == 0 and off.export()["traceEvents"] == []
+
+
+def test_many_recorders_share_one_runtime_hook():
+    recs = [TraceRecorder(capacity=8) for _ in range(50)]
+    listeners = jax._src.monitoring.get_event_duration_listeners()
+    assert sum(cb is tracing._on_jax_duration for cb in listeners) == 1
+    assert sum(cb is tracing._on_gc for cb in gc.callbacks) == 1
+    # each live enabled recorder is forwarded to; disabled ones are not
+    live = tracing._runtime_recorders()
+    assert all(r in live for r in recs)
+    del recs, live
+    gc.collect()
+
+
+def test_long_gc_is_recorded_as_py_gc():
+    tr = TraceRecorder(capacity=1 << 12)
+    # enough tracked containers that a full collection takes > 1 ms
+    junk = [[i] for i in range(400_000)]
+    t0 = time.perf_counter()
+    gc.collect()
+    took = time.perf_counter() - t0
+    del junk
+    assert took > tracing.GC_MIN_S
+    spans = [e for e in _runtime(tr, "py.gc") if e["args"]["gen"] == 2]
+    assert spans, _runtime(tr)
+    assert max(e["dur"] for e in spans) >= 1e6 * tracing.GC_MIN_S
+    assert spans[-1]["tid"] == threading.get_ident()
+
+
 # --------------------------------------------------------------------------
 # the validator must reject hand-built garbage
 # --------------------------------------------------------------------------
@@ -276,27 +368,32 @@ def test_check_trace_flags_uncovered_tokens():
     span = [_ev("request", "b", 100.0, args={"rid": 1}),
             _ev("request", "e", 200.0, args={"rid": 1})]
     outside = {"traceEvents": span + [
-        _ev("tok.stream", "i", 300.0, args={"rid": 1})],
+        _ev("detok", "X", 300.0, dur=5.0, args={"rid": 1})],
         "otherData": {"capacity": 10, "dropped": 0}}
     assert any("outside" in p for p in check_trace(outside))
+    # a batch that starts inside but runs past the request's end
+    straddle = {"traceEvents": span + [
+        _ev("detok", "X", 195.0, dur=20.0, args={"rid": 1})],
+        "otherData": {"capacity": 10, "dropped": 0}}
+    assert any("outside" in p for p in check_trace(straddle))
     inside = {"traceEvents": span + [
-        _ev("tok.stream", "i", 150.0, args={"rid": 1})],
+        _ev("detok", "X", 150.0, dur=5.0, args={"rid": 1})],
         "otherData": {"capacity": 10, "dropped": 0}}
     assert not check_trace(inside)
     # no "b" at all: a defect in a complete export...
     orphan = {"traceEvents": [
-        _ev("tok.stream", "i", 150.0, args={"rid": 2})],
+        _ev("detok", "X", 150.0, dur=5.0, args={"rid": 2})],
         "otherData": {"capacity": 10, "dropped": 0}}
     assert any("no request" in p for p in check_trace(orphan))
     # ...but tolerated when the ring dropped events or was windowed
     lossy = {"traceEvents": [
-        _ev("tok.stream", "i", 150.0, args={"rid": 2})],
+        _ev("detok", "X", 150.0, dur=5.0, args={"rid": 2})],
         "otherData": {"capacity": 10, "dropped": 5}}
     assert not check_trace(lossy)
     # in-flight request: open window extends to +inf
     inflight = {"traceEvents": [
         _ev("request", "b", 100.0, args={"rid": 3}),
-        _ev("tok.stream", "i", 500.0, args={"rid": 3})],
+        _ev("detok", "X", 500.0, dur=5.0, args={"rid": 3})],
         "otherData": {"capacity": 10, "dropped": 0}}
     assert not check_trace(inflight)
 
@@ -406,9 +503,11 @@ def test_pipeline_trace_validates_and_carries_timing(lm):
     problems = check_trace(out)
     assert not problems, "\n".join(problems)
     names = {e["name"] for e in out["traceEvents"]}
-    for need in ("request", "req.submit", "tok.stream", "detok",
-                 "engine.step", "decode.chunk", "req.retire"):
+    for need in ("request", "req.submit", "detok", "engine.step",
+                 "decode.chunk", "decode.dispatch", "decode.wait",
+                 "decode.post", "req.retire"):
         assert need in names, f"missing {need!r} (have {sorted(names)})"
+    assert "tok.stream" not in names  # detok spans carry the batches
     assert names & {"engine.prefill", "prefill.packed", "prefill.chunk"}
     # one async b/e pair per request
     b = [e for e in out["traceEvents"] if e["ph"] == "b"]
@@ -452,6 +551,86 @@ def test_pipeline_adopts_enabled_engine_recorder(lm):
     assert pipe3.trace is off and not pipe3.trace.enabled
     eng2.step_listeners.clear()
     eng3.step_listeners.clear()
+
+
+def _nested_in(inner, outer) -> bool:
+    return (outer["ts"] - 1.0 <= inner["ts"] and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + 1.0)
+
+
+def test_engine_step_records_leaf_spans(lm):
+    """One step with an admission: the admission's and the decode
+    quantum's leaf spans, each inside the step's engine.step span."""
+    model, params = lm
+    tr = TraceRecorder(capacity=1 << 14)
+    eng = _mk_engine(model, params, policy="int4-srft", paged=True,
+                     prefill_chunk=32, trace=tr)
+    prompt = np.arange(32, dtype=np.int32) % SMOL_D64.vocab_size
+    eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=6))
+    eng.step()
+    out = tr.export()
+    assert not check_trace(out)
+    evs = [e for e in out["traceEvents"] if e["ph"] == "X"]
+    (step,) = [e for e in evs if e["name"] == "engine.step"]
+    by = {}
+    for e in evs:
+        by.setdefault(e["name"], []).append(e)
+    leaves = ("admit.start", "prefill.chunk", "admit.insert",
+              "decode.dispatch", "decode.wait", "decode.chunk",
+              "decode.post")
+    for name in leaves:
+        assert name in by, f"missing {name!r} (have {sorted(by)})"
+        for e in by[name]:
+            assert _nested_in(e, step), name
+            assert e["tid"] == step["tid"]
+    # in order, and the quantum splits into dispatch and wait
+    starts = [by[n][0]["ts"] for n in leaves if n != "decode.chunk"]
+    assert starts == sorted(starts)
+    (chunk,) = by["decode.chunk"]
+    (disp,) = by["decode.dispatch"]
+    (wait,) = by["decode.wait"]
+    (post,) = by["decode.post"]
+    assert disp["ts"] == chunk["ts"]
+    assert wait["ts"] == pytest.approx(disp["ts"] + disp["dur"], abs=1e-2)
+    assert wait["ts"] + wait["dur"] == pytest.approx(
+        chunk["ts"] + chunk["dur"], abs=1e-2)
+    assert post["ts"] == pytest.approx(chunk["ts"] + chunk["dur"], abs=1e-2)
+    assert chunk["args"] == {"steps": 4, "rows": 1, "capacity": CAPACITY,
+                             "spec": False}
+    assert by["admit.insert"][0]["args"]["inserted"] is True
+    # the quantum's first call compiled inside its dispatch
+    compiles = [e for e in _runtime(tr, "jax.compile")
+                if e["args"]["fun"] == "jit(decode_quantum)"]
+    assert compiles and all(_nested_in(e, disp) for e in compiles)
+
+
+def test_admit_hold_is_one_span_per_hold(lm):
+    """A partial group that arrives while the engine decodes is held
+    for up to ``admit_hold_s`` in ~1 ms beats; the hold is one span from
+    its first beat to the admit, not an instant per beat."""
+    model, params = lm
+    eng = _mk_engine(model, params, policy="bf16")
+    trace = TraceRecorder(capacity=1 << 14)
+    eng.trace = trace
+    pipe = ServingPipeline(eng, max_group=eng.capacity, admit_queue=8,
+                           admit_hold_s=0.05, trace=trace).start()
+    prompt = np.arange(16, dtype=np.int32)
+    busy = pipe.submit(Request(rid=0, prompt=prompt, max_new_tokens=30))
+    deadline = time.monotonic() + 60.0
+    while not eng.active.any():  # the engine is decoding
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+    held = pipe.submit(Request(rid=1, prompt=prompt, max_new_tokens=2))
+    drain_stream(held, timeout=60.0)
+    drain_stream(busy, timeout=60.0)
+    assert pipe.shutdown(timeout=60.0)
+    out = trace.export()
+    assert not check_trace(out)
+    holds = [e for e in out["traceEvents"] if e["name"] == "admit.hold"]
+    assert holds and all(e["ph"] == "X" for e in holds)
+    assert len(holds) <= 2  # one per admission at most
+    assert max(e["dur"] for e in holds) >= 10_000  # us: beats merged
+    assert set(holds[0]["args"]) == {"head_group", "depth"}
 
 
 # --------------------------------------------------------------------------
