@@ -983,8 +983,8 @@ class Int4SRFTPolicy:
             backend = AttendBackend.BLOCKWISE
         if backend is AttendBackend.KERNEL and is_paged:
             # paged kernel: the page table rides the scalar prefetch and
-            # the grid walks physical pages (one tile per page) -- the
-            # dense view is never materialized.
+            # each grid step gathers several physical pages of one row
+            # -- the dense view is never materialized.
             from repro.kernels.quant_attention import (
                 decode_attention_kernel_paged,
             )

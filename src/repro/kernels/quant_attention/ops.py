@@ -71,10 +71,11 @@ def decode_attention_kernel_paged(
 ) -> jax.Array:
     """(B, Hq, 1, d) decode attention over a PAGED int4 cache.
 
-    The page table rides the scalar prefetch; the kernel's grid walks
-    physical pages (one tile per page -- the paged prefetch contract,
-    DESIGN.md §10), so the dense per-row view is never materialized and
-    HBM residency is the pool, not O(B x s_max).
+    The page table rides the scalar prefetch; each grid step of the
+    kernel gathers several pages of one row from the pools in HBM (the
+    paged prefetch contract, DESIGN.md §10), so the dense per-row view
+    is never materialized and HBM residency is the pool, not O(B x
+    s_max).
     """
     B, Hq, _, d = q.shape
     kp_pool, ks_pool, vp_pool, vs_pool = pd.pools

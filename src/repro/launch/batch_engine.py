@@ -99,6 +99,7 @@ import numpy as np
 
 from repro.core.cache_api import AttendBackend
 from repro.core.paged import NULL_PAGE, PagedData
+from repro.kernels.quant_attention.quant_attention import paged_tile_pages
 from repro.launch.engine import (
     GREEDY, Sampler, draft_tokens, resolve_mesh_backend, _serve_policy_ctx,
 )
@@ -1710,6 +1711,26 @@ class BatchEngine:
             if spent >= self.prefill_budget:
                 return
 
+    def _kv_tiles(self) -> dict:
+        """Live and total grid tiles of one paged-kernel call at the
+        quantum's first step, from the host's own lengths (no device
+        read): ``kv_tiles_live / kv_tiles_grid`` is the share of the
+        kernel's grid steps that do work.  Empty off the kernel path."""
+        if not (self.paged and self.spec_k is None
+                and self.backend is AttendBackend.KERNEL):
+            return {}
+        pages = paged_tile_pages(self.page_size, self.max_pages)
+        tile = pages * self.page_size
+        live = 0
+        for slot in np.nonzero(self.active)[0]:
+            req = self._slot_req[slot]
+            # the step appends the slot's last token, then attends
+            n = (len(req.prompt) + (req.resume_tok is not None)
+                 + len(self._slot_toks[slot]))
+            live += -(-(n - n % self._align) // tile)
+        return {"kv_tiles_live": live,
+                "kv_tiles_grid": self.capacity * -(-self.max_pages // pages)}
+
     def step(self) -> tuple[list[tuple[int, list[int]]], list[Completion]]:
         """One scheduler quantum: admit into free slots (monolithic
         prefill, or up to ``prefill_budget`` tokens of chunked prefill),
@@ -1752,6 +1773,7 @@ class BatchEngine:
         # the host work after it
         t0d = time.perf_counter()
         n_live = int(self.active.sum())
+        kv_tiles = self._kv_tiles()
         self._sample_key, sub = jax.random.split(self._sample_key)
         if self.spec_k is not None:
             # each scan step is one verify pass emitting 1..spec_k
@@ -1784,7 +1806,7 @@ class BatchEngine:
         tr.span_at("decode.wait", t_disp, cat="decode", t1=t_read)
         tr.span_at("decode.chunk", t0d, cat="decode", t1=t_read,
                    steps=n_steps, rows=n_live, capacity=self.capacity,
-                   spec=self.spec_k is not None)
+                   spec=self.spec_k is not None, **kv_tiles)
         if self.spec_k is not None:
             tr.instant("spec.verify", cat="spec", drafted=nd, accepted=na,
                        rejected=nd - na)

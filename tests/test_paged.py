@@ -40,6 +40,7 @@ except ImportError:  # pragma: no cover - exercised by the fast CI lane
 from repro.configs.paper_models import SMOL_D64
 from repro.core import paged as P
 from repro.core.cache_api import available_policies, get_policy
+from repro.kernels.quant_attention.quant_attention import paged_tile_pages
 from repro.launch.batch_engine import BatchEngine, Request
 from repro.models import build_model
 
@@ -223,7 +224,10 @@ def test_pool_validation_and_null_page():
 # ---------------------------------------------------------------------------
 
 S_MAX = 64
-PAGE = 32  # == kv_block: dense and paged kernels then tile identically
+PAGE = 32
+# the paged kernel's tile (P pages); dense engines run kv_block == TILE,
+# so dense and paged kernels then tile identically
+TILE = paged_tile_pages(PAGE, S_MAX // PAGE) * PAGE
 RAGGED_PROMPTS = (9, 17, 23)
 RAGGED_NEW = (12, 20, 7)
 
@@ -244,7 +248,7 @@ def _prompts(lens, base=40):
 def _run_engine(model, params, reqs, *, policy, backend, paged,
                 capacity=3, s_max=S_MAX, **kw):
     eng = BatchEngine(model, params, capacity=capacity, s_max=s_max,
-                      policy=policy, backend=backend, kv_block=PAGE,
+                      policy=policy, backend=backend, kv_block=TILE,
                       chunk=4, key=jax.random.PRNGKey(7), paged=paged, **kw)
     got = {c.rid: c for c in eng.run(list(reqs))}
     return eng, got
@@ -265,7 +269,7 @@ def test_paged_engine_matches_dense_engine(lm, policy, backend):
     """ISSUE-4 acceptance oracle: paged decode == dense ragged decode,
     bit for bit per row, for every policy x supported backend.  The
     kernel case exercises the paged Pallas path (page-table scalar
-    prefetch, one tile per page) in interpret mode."""
+    prefetch, P pages a tile) in interpret mode."""
     model, params = lm
     reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
             for i, (p, n) in enumerate(zip(_prompts(RAGGED_PROMPTS),
@@ -437,16 +441,37 @@ def test_paged_engine_validation(lm):
 # Paged Pallas kernel unit test (page-table indirection)
 # ---------------------------------------------------------------------------
 
-def test_paged_kernel_walks_shuffled_pages():
+# (G, flush window W, s_max, slot 0's first length, final lengths of
+# slots 0 and 1).  16-token pages: a tile is P = min(MP, 16) pages.
+SHUFFLE_CASES = {
+    # one tile a row (MP = 4 = P), both rows ending in their last page
+    "one_tile": (2, 16, 64, 22, (37, 37)),
+    # MP = 36, not a multiple of P = 16; slot 1 holds residual tokens only
+    # (packed_len 0); slot 0's packed prefix ends mid-tile at a page edge
+    "residual_only_row": (2, 16, 576, 22, (300, 9)),
+    # G = 5; slot 0's packed prefix ends exactly on the first tile's end,
+    # slot 1 has 2 live pages, fewer than P
+    "tile_boundary_g5": (5, 16, 576, 22, (261, 40)),
+    # W = 8 < page: slot 0's packed prefix (520) ends mid-tile AND mid-page
+    "mid_page": (2, 8, 576, 22, (527, 100)),
+}
+
+
+@pytest.mark.parametrize("case", list(SHUFFLE_CASES))
+def test_paged_kernel_walks_shuffled_pages(case):
     """The paged kernel must follow the page table, not physical page
     order: decode attention over a row whose pages are deliberately
     NON-CONTIGUOUS (allocated across a free/realloc cycle) matches the
-    gather oracle on the same state."""
-    pol = get_policy("int4-srft", group=8, window=16)
-    B, H, S, D = 2, 2, 64, 32
+    gather oracle on the same state, wherever each row's packed prefix
+    ends against the kernel's many-page tiles."""
+    G, window, S, first, (len0, len1) = SHUFFLE_CASES[case]
+    ps = 16
+    pol = get_policy("int4-srft", group=8, window=window)
+    B, H, D = 2, 2, 32
     key = jax.random.PRNGKey(3)
-    state = pol.init_paged(B, H, S, D, n_pages=12, page_size=16, key=key)
-    MP = S // 16
+    MP = S // ps
+    state = pol.init_paged(B, H, S, D, n_pages=2 * MP + 1, page_size=ps,
+                           key=key)
     nul = jnp.full((MP,), P.NULL_PAGE, jnp.int32)
 
     def admit(state, slot, L, seed):
@@ -457,19 +482,22 @@ def test_paged_kernel_walks_shuffled_pages():
         row = pol.prefill(row, k, v)
         return pol.insert_row_paged(state, row, jnp.asarray(slot), nul,
                                     jnp.asarray(0),
-                                    jnp.asarray(-(-L // 16)))
+                                    jnp.asarray(-(-L // ps)))
 
-    # slot0 takes pages 1-2, slot1 takes 3-5; freeing slot0 and
-    # re-admitting a LONGER row reuses 1-2 and jumps to 6: [1, 2, 6]
-    state = admit(state, 0, 22, 0)
-    state = admit(state, 1, 37, 1)
+    # slot0 takes the first pages, slot1 the next ones; freeing slot0 and
+    # re-admitting a LONGER row reuses slot0's pages and then jumps past
+    # slot1's (e.g. [1, 2, 6])
+    state = admit(state, 0, first, 0)
+    state = admit(state, 1, len1, 1)
     state = pol.reset_rows(state, jnp.asarray([True, False]))
-    state = admit(state, 0, 37, 2)
+    state = admit(state, 0, len0, 2)
     ptab = np.asarray(state.data.kv.page_table)
     mapped = ptab[0][ptab[0] != P.NULL_PAGE]
     assert (np.diff(mapped) != 1).any(), \
         f"expected non-contiguous pages, got {ptab[0]}"
-    q = jax.random.normal(jax.random.fold_in(key, 77), (B, 2 * H, 1, D))
+    np.testing.assert_array_equal(np.asarray(state.data.kv.length),
+                                  [len0, len1])
+    q = jax.random.normal(jax.random.fold_in(key, 77), (B, G * H, 1, D))
     out_k = pol.attend(q, state, backend="kernel")
     out_g = pol.attend(q, state, backend="gather")
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_g),

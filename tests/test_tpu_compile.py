@@ -71,12 +71,16 @@ def test_dense_decode_kernel_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_kernel_compiles(one_chip):
-    mp = PREFIX // PAGE
-    n_pages = B * mp + 1  # every row's pages plus the null page
-    shapes = ([((BH, G, D), jnp.float32)] + _kv(n_pages * HKV, PAGE)
-              + [((BH, W, D), jnp.float32)] * 2
-              + [((BH,), jnp.int32)] * 2 + [((B, mp), jnp.int32)])
+# (batch, pages a row): the module's 4096-token batch of 8, and the
+# long_docs benchmark cell's 5 slots of 6544 tokens (409 pages, which
+# the kernel's tile of 16 pages does not divide)
+@pytest.mark.parametrize("b,mp", [(B, PREFIX // PAGE), (5, 409)])
+def test_paged_decode_kernel_compiles(one_chip, b, mp):
+    bh = b * HKV
+    n_pages = b * mp + 1  # every row's pages plus the null page
+    shapes = ([((bh, G, D), jnp.float32)] + _kv(n_pages * HKV, PAGE)
+              + [((bh, W, D), jnp.float32)] * 2
+              + [((bh,), jnp.int32)] * 2 + [((b, mp), jnp.int32)])
     text = _compiled_text(
         lambda *a: quant_decode_attention_paged_fwd(
             *a, group=GROUP, page_size=PAGE, n_kv_heads=HKV,

@@ -35,6 +35,7 @@ import pytest
 
 from repro.configs.paper_models import SMOL_D64
 from repro.core.cache_api import available_policies
+from repro.kernels.quant_attention.quant_attention import paged_tile_pages
 from repro.launch.batch_engine import BatchEngine, Request
 from repro.launch.server import (
     ServingPipeline,
@@ -602,6 +603,37 @@ def test_engine_step_records_leaf_spans(lm):
     compiles = [e for e in _runtime(tr, "jax.compile")
                 if e["args"]["fun"] == "jit(decode_quantum)"]
     assert compiles and all(_nested_in(e, disp) for e in compiles)
+
+
+def test_decode_chunk_counts_live_kernel_tiles(lm):
+    """On the paged kernel path each ``decode.chunk`` span carries the
+    kernel's live and total grid tiles at the quantum's first step, from
+    the host's own lengths: they agree with the device's row lengths,
+    including a quantum whose first step lands exactly on a flush."""
+    model, params = lm
+    tr = TraceRecorder(capacity=1 << 14)
+    # 20 pages a row: 16-page (256-token) tiles, two a row
+    eng = BatchEngine(model, params, capacity=3, s_max=320,
+                      policy="int4-srft", backend="kernel", chunk=4,
+                      key=jax.random.PRNGKey(7), paged=True, page_size=16,
+                      trace=tr)
+    W = model.cache_policy("int4-srft").window
+    tile = paged_tile_pages(16, eng.max_pages) * 16
+    assert (W, tile) == (16, 256)
+    # the long row's quanta start at 268, 272 (a flush), 276 tokens
+    for rid, n in enumerate((267, 30)):
+        eng.submit(Request(rid=rid, prompt=np.arange(n, dtype=np.int32)
+                           % SMOL_D64.vocab_size, max_new_tokens=20))
+    expected = []
+    for _ in range(3):
+        eng.step()
+        after = np.asarray(eng.cache["attn"].data.kv.length[0])
+        first = after[:2] - 4 + 1  # both rows ran the whole quantum
+        expected.append(int(sum(-(-(n - n % W) // tile) for n in first)))
+    chunks = [e["args"] for e in tr.export()["traceEvents"]
+              if e["name"] == "decode.chunk"]
+    assert [c["kv_tiles_live"] for c in chunks] == expected == [2, 3, 3]
+    assert all(c["kv_tiles_grid"] == 3 * 2 for c in chunks)
 
 
 def test_admit_hold_is_one_span_per_hold(lm):
