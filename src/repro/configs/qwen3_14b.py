@@ -1,5 +1,7 @@
-"""qwen3-14b [dense]: 40L d_model=5120 40H (GQA kv=8) d_ff=17408
-vocab=151936, qk_norm [hf:Qwen/Qwen3-8B family]."""
+"""qwen3-14b [dense]: 40L d_model=5120 40H (GQA kv=8) head_dim=128
+d_ff=17408 vocab=151936, per-head q/k RMSNorm, rms_norm_eps 1e-6 (the
+``norm_eps`` default), untied head, max_position_embeddings 40960
+[huggingface.co/Qwen/Qwen3-14B]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
